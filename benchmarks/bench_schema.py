@@ -61,13 +61,10 @@ _XBATCH_MODE = {"wall_s": _NUM, "samples_per_s": _NUM, "launches": _NUM}
 
 
 def _mesh_spec(doc: Any) -> Optional[str]:
-    """mesh_dispatch may be {"skipped": reason} (no subprocess support) or
-    the full result; both are schema-valid, silence is not."""
+    """mesh_dispatch is the full result: a failed child raises in the
+    bench instead of leaving a placeholder."""
     if not isinstance(doc, dict):
         return f"expected object, got {type(doc).__name__}"
-    if "skipped" in doc:
-        return None if isinstance(doc["skipped"], str) else \
-            "skipped must carry a reason string"
     errs: List[str] = []
     _check_node(doc, {
         "devices": _NUM, "bucket_bound": _NUM, "bit_equal": bool,

@@ -87,7 +87,7 @@ def main() -> None:
                      f"{xb['per_worker']['launches']} -> "
                      f"{xb['xbatch']['launches']}"))
         md = et.get("mesh_dispatch", {})
-        if md and "skipped" not in md:
+        if md:
             rows.append(("ensemble_mesh_dispatch",
                          1e6 / md["jag_sharded"]["samples_per_s"],
                          f"{md['devices']} forced host devices; "

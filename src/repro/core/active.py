@@ -211,7 +211,8 @@ class SurrogateSnapshot:
     def refresh(self) -> bool:
         """Pull new bundles since the last refresh and retrain if at least
         ``min_new_rows`` accumulated; returns True when the served model
-        changed (``version`` bumped)."""
+        changed (``version`` bumped).  Rows whose objective is not finite
+        or whose ``failed`` flag is set are not trained on."""
         with self._lock:
             data, self._cursor = self.bundler.load_since(self._cursor)
             X_new = data.get(self.input_key)
@@ -221,6 +222,13 @@ class SurrogateSnapshot:
                 y_new = np.asarray(y_new, np.float32).reshape(len(X_new))
                 if X_new.ndim == 1:
                     X_new = X_new[:, None]
+                # failed shots carry a NaN objective (sim/jag.py); one NaN
+                # row would turn every prediction NaN, so fit only the rows
+                # regression_dataset keeps
+                ok = np.isfinite(y_new)
+                if "failed" in data:
+                    ok &= np.asarray(data["failed"]).reshape(len(ok)) < 0.5
+                X_new, y_new = X_new[ok], y_new[ok]
                 if self._X is None:
                     self._X, self._y = X_new, y_new
                 else:

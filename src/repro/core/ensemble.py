@@ -188,11 +188,10 @@ class EnsembleExecutor:
             # independent (per-row rng from the row's seed), so the split
             # is bit-for-bit identical to the single-device vmap — the
             # multi-device equivalence test asserts exactly that.
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
             spec = P(self.data_axis)
-            sharded = shard_map(run, mesh=self.mesh,
-                                in_specs=(spec, spec), out_specs=spec)
+            sharded = jax.shard_map(run, mesh=self.mesh,
+                                    in_specs=(spec, spec), out_specs=spec)
             return jax.jit(sharded, donate_argnums=donate)
         return jax.jit(run, donate_argnums=donate)
 

@@ -34,6 +34,14 @@ argument                  env var                  effect
 ``debug_nans``            ``REPRO_DEBUG_NANS``     ``JAX_DEBUG_NANS``
 ========================  =======================  =========================
 
+Persistent compilation cache: an exported ``JAX_COMPILATION_CACHE_DIR``
+is used as is.  Otherwise the cache lives at the fixed in-checkout path
+``<repo>/.jax_cache`` (derived from this file's location, never from a
+temp name, pid or time: the path is part of the cache key, so a moving
+directory would never hit).  The variable is exported so child processes
+share the same cache; when jax is already imported the directory is also
+applied through ``jax.config``.
+
 Thread pinning uses ``setdefault``: an operator who already exported
 ``OMP_NUM_THREADS=4`` wins over our default, but an unpinned shell gets
 a deterministic count instead of library roulette.  XLA/JAX env flags
@@ -58,6 +66,10 @@ from typing import Any, Dict, Optional
 _THREAD_VARS = ("OMP_NUM_THREADS", "OPENBLAS_NUM_THREADS",
                 "MKL_NUM_THREADS", "NUMEXPR_NUM_THREADS")
 _DEVICE_FLAG = "--xla_force_host_platform_device_count"
+_CACHE_VAR = "JAX_COMPILATION_CACHE_DIR"
+_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+DEFAULT_CACHE_DIR = os.path.join(_REPO_ROOT, ".jax_cache")
 
 _applied: Optional[Dict[str, Any]] = None
 
@@ -137,6 +149,13 @@ def configure(x64: Optional[bool] = None, platform: Optional[str] = None,
     if xla_parts:
         os.environ["XLA_FLAGS"] = " ".join(xla_parts)
 
+    cache_dir = os.environ.get(_CACHE_VAR) or None
+    if cache_dir is None:
+        cache_dir = os.environ[_CACHE_VAR] = DEFAULT_CACHE_DIR
+        if jax_preimported:
+            import jax
+            jax.config.update("jax_compilation_cache_dir", cache_dir)
+
     if not jax_preimported:
         if x64 is not None:
             os.environ["JAX_ENABLE_X64"] = "1" if x64 else "0"
@@ -168,6 +187,7 @@ def configure(x64: Optional[bool] = None, platform: Optional[str] = None,
         "debug_nans": debug_nans,
         "tcmalloc": tcmalloc,
         "jax_preimported": jax_preimported,
+        "compilation_cache_dir": cache_dir,
     }
     return dict(_applied)
 

@@ -1,6 +1,8 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede any jax import: jax locks the device count at first init.
+os.environ["JAX_PLATFORMS"] = "cpu"
+# ^ MUST precede any jax import: jax locks the device count at first init,
+# and the 512 forced devices are host devices (never the accelerator).
 # This flag lives ONLY here (and in subprocesses spawned from here) so smoke
 # tests and benchmarks keep seeing one real device.
 #

@@ -17,7 +17,6 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
@@ -51,8 +50,8 @@ def compressed_grad_allreduce(grads, mesh: Mesh, axis: str = "data",
     """
     def one(g):
         @functools.partial(
-            shard_map, mesh=mesh, in_specs=P(axis),
-            out_specs=P(), check_rep=False)
+            jax.shard_map, mesh=mesh, in_specs=P(axis),
+            out_specs=P(), check_vma=False)
         def reduce_fn(gs):
             return int8_psum(gs.sum(axis=0), axis, bits=bits)
 

@@ -20,15 +20,17 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-EPI_BOUNDS = jnp.array([
+# host constant: a device array here would start a JAX backend at import
+EPI_BOUNDS = np.array([
     [0.15, 0.60],
     [2.0, 5.0],
     [3.0, 8.0],
     [-5.0, -3.0],   # log10 seed
     [0.0, 0.8],
     [5.0, 40.0],
-])
+], np.float32)
 
 N_PATCH = 16
 T_DAYS = 60

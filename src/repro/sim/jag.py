@@ -21,14 +21,16 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-JAG_BOUNDS = jnp.array([
+# host constant: a device array here would start a JAX backend at import
+JAG_BOUNDS = np.array([
     [0.85, 1.15],
     [-0.10, 0.10],
     [-0.08, 0.08],
     [-0.08, 0.08],
     [0.00, 0.08],
-])
+], np.float32)
 
 N_T = 32          # time-series samples
 IMG = 16          # image resolution

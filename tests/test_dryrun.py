@@ -14,13 +14,14 @@ import pytest
 # that in-process tests see ONE device — leaking 512 would silently flip
 # every later-initializing jax test (e.g. the ensemble auto-mesh) into a
 # forced-multi-device process.
-_saved_xla_flags = os.environ.get("XLA_FLAGS")
+_saved_env = {k: os.environ.get(k) for k in ("XLA_FLAGS", "JAX_PLATFORMS")}
 from repro.launch.dryrun import _shape_bytes, collective_bytes  # noqa: E402
 
-if _saved_xla_flags is None:
-    os.environ.pop("XLA_FLAGS", None)
-else:
-    os.environ["XLA_FLAGS"] = _saved_xla_flags
+for _k, _v in _saved_env.items():
+    if _v is None:
+        os.environ.pop(_k, None)
+    else:
+        os.environ[_k] = _v
 
 HLO = """
 ENTRY main {

@@ -321,9 +321,8 @@ def test_ensemble_bench_smoke(tmp_path):
     assert xb["per_worker"]["samples_per_s"] > 0
     assert xb["xbatch"]["samples_per_s"] > 0
     assert xb["xbatch"]["engine"]["batches"] >= 1
-    md = r["mesh_dispatch"]
-    if "skipped" not in md:  # subprocess ran: equivalence must hold
-        assert md["bit_equal"] is True
-        assert md["jag_max_rel_diff"] <= 1e-3
+    md = r["mesh_dispatch"]  # a failed child raises inside run()
+    assert md["bit_equal"] is True
+    assert md["jag_max_rel_diff"] <= 1e-3
     assert r["surrogate"]["prediction_max_abs_diff"] < 1e-2
     assert r["loads"]["warm_load_s"] <= r["loads"]["cold_load_s"]

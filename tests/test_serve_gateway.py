@@ -170,6 +170,46 @@ def test_refresh_folds_new_bundles(tmp_path):
         assert st == 200 and out["version"] == 2
 
 
+def _jag_archive(root, n=256, n_failed=12, seed=0):
+    """A real JAG archive whose failed shots carry NaN yield."""
+    import jax
+    from repro.sim import jag_simulate
+    rng = np.random.default_rng(seed)
+    U = rng.random((n, 5)).astype(np.float32)
+    # over-driven thin shells are the simulator's failure region: put
+    # exactly n_failed rows there and keep the drive of the rest below it
+    U[n_failed:, 0] *= 0.9
+    U[:n_failed, 0] = 0.99
+    U[:n_failed, 1] = 0.01
+    keys = jax.vmap(jax.random.PRNGKey)(np.arange(n, dtype=np.uint32))
+    out = {k: np.asarray(v)
+           for k, v in jax.jit(jax.vmap(jag_simulate))(U, keys).items()}
+    assert np.isnan(out["yield"]).sum() == n_failed
+    out["yield"] = out["yield"] / 5e15  # keep the fit in a tame range
+    Bundler(root).write_bundle(0, n, out)
+    return U
+
+
+def test_snapshot_skips_failed_shots(tmp_path):
+    """One NaN row used to turn every prediction NaN: the snapshot trains
+    only on finite, non-failed rows, and so does the served model."""
+    U = _jag_archive(str(tmp_path))
+    snap = _tiny_snapshot(str(tmp_path))
+    assert snap.rows == 256 - 12
+    mu, sd = snap.predict(U)
+    assert np.isfinite(mu).all() and np.isfinite(sd).all()
+    with SurrogateGateway(snap) as gw:
+        st, out = _post(gw.port, "/v1/predict", {"points": U[:32].tolist()})
+    assert st == 200
+    assert np.isfinite(out["mu"]).all() and np.isfinite(out["sigma"]).all()
+
+
+def test_snapshot_of_only_failed_shots_has_no_training_rows(tmp_path):
+    _jag_archive(str(tmp_path), n=8, n_failed=8)
+    with pytest.raises(ValueError, match="no training rows"):
+        _tiny_snapshot(str(tmp_path))
+
+
 # ---------------------------------------------------------------------------
 # status-mapping contract (stub snapshot: no jax in the loop)
 # ---------------------------------------------------------------------------
