@@ -38,6 +38,7 @@ import time
 import numpy as np
 
 from repro.core import Bundler, EnsembleExecutor, MerlinRuntime, Step, StudySpec, WorkerPool
+from repro.core import spans
 from repro.core.active import train_surrogate
 from repro.core.hierarchy import HierarchyCfg
 from repro.data.pipeline import regression_dataset
@@ -112,12 +113,17 @@ def main(argv=None):
             samples = np.asarray(jag_sample_inputs(jax.random.PRNGKey(0), 512))
 
             # 2. producer-consumer execution ---------------------------------
+            t_run = time.perf_counter()
             with WorkerPool(rt, n_workers=4) as pool:
                 study = rt.run(spec, samples)      # `merlin run`: one message
                 assert rt.wait(study, timeout=120)
+                t = spans.totals()
                 print(f"workers processed {pool.stats()['real']} bundles "
-                      f"({executor.stats['samples']} simulations, "
-                      f"{executor.stats['sim_time']:.2f}s device time)")
+                      f"({executor.stats['samples']} simulations in "
+                      f"{time.perf_counter() - t_run:.2f}s; launches "
+                      f"{t['executor.launch']['s']:.2f}s, host sync "
+                      f"{t['executor.host_sync']['s']:.2f}s, bundle writes "
+                      f"{t['bundler.write']['s']:.2f}s)")
 
             # 3. ML-ready: train a surrogate on the ensemble -----------------
             data = bundler.load_all()

@@ -33,6 +33,8 @@ from typing import Dict, Iterable, List, Mapping, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
+from repro.core import spans
+
 # a file's identity-and-content signature: (inode, mtime_ns, size)
 Sig = Tuple[int, int, int]
 
@@ -64,14 +66,16 @@ class Bundler:
         return d
 
     def write_bundle(self, lo: int, hi: int, results: Dict[str, np.ndarray]) -> str:
-        """results: dict of arrays with leading dim == hi-lo."""
+        """results: dict of arrays with leading dim == hi-lo.  The
+        compression and the publish are the span ``bundler.write``."""
         d = self.leaf_dir(lo, hi - lo)
         path = os.path.join(d, f"bundle_{lo:09d}_{hi:09d}.npz")
         # np.savez appends ".npz" unless present: keep the suffix on the tmp
         tmp = os.path.join(d, f".tmp-{os.getpid()}-{lo}-{hi}.npz")
         ids = np.arange(lo, hi)
-        np.savez_compressed(tmp, _sample_ids=ids, **results)
-        os.rename(tmp, path)  # atomic publish
+        with spans.span("bundler.write", task=lo):
+            np.savez_compressed(tmp, _sample_ids=ids, **results)
+            os.rename(tmp, path)  # atomic publish
         if self.sink is not None:
             try:
                 self.sink.push_bundle(lo, hi, results)

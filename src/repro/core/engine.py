@@ -31,10 +31,14 @@ testable component:
   poison task.
 * **Observability.**  ``stats()`` reports batches fused, a tasks-per-
   batch histogram, how many flushes were triggered by size vs deadline
-  vs an explicit ``flush()``, and the busy fraction (the share of
-  wall-clock the engine spent inside fused execution — the scheduler's
-  proxy for device utilization; the sample-level view, real vs padded
-  device rows, is ``EnsembleExecutor.stats``).
+  vs an explicit ``flush()``, and ``utilization``: the share of wall-
+  clock since the first submission that the engine's threads spent
+  dispatching and finalizing batches.  That is host time, not the
+  device's: the device's busy share comes from a profiler trace, and
+  real vs padded device rows from ``EnsembleExecutor.stats``.  It also
+  carries the process's span totals (:mod:`repro.core.spans`) as
+  ``span_s.<name>`` and ``span_n.<name>``, so that a reader who diffs
+  two snapshots reads each layer's host seconds and counts in between.
 
 Lifecycle: engines are shared and reference-counted.  ``MerlinRuntime.
 shared_engine()`` hands every WorkerPool of a runtime the same instance
@@ -96,6 +100,7 @@ from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from repro.core import spans
 from repro.core.queue import BrokerFull, Task
 
 
@@ -106,6 +111,15 @@ class EngineClosed(RuntimeError):
 class DeadlineExpired(RuntimeError):
     """A serve request's deadline passed before it was admitted to a
     batch; it was dropped without executing (the gateway maps it to 504)."""
+
+
+def task_lo(task: Task) -> int:
+    """Lowest sample id of a study task, or -1 when it carries none: the
+    id its spans carry (a fused launch's spans carry its tasks' lowest)."""
+    try:
+        return int(task.payload["samples"][0])
+    except (KeyError, IndexError, TypeError):
+        return -1
 
 
 class PendingTask:
@@ -322,6 +336,7 @@ class ExecutionEngine:
                 else:
                     reason = "deadline_flushes"
                 batch = group[:self.max_batch]
+                taken_at = time.monotonic()
                 taken = set(map(id, batch))
                 self._buf = [p for p in self._buf if id(p) not in taken]
                 if self._buf:
@@ -334,6 +349,9 @@ class ExecutionEngine:
                     self._deadline = self._buf[0].submitted_at + self.max_wait
                 else:
                     self._flush_asked = False
+            spans.count("engine.queue",
+                        sum(taken_at - p.submitted_at for p in batch),
+                        len(batch))
             self._execute(batch, reason)
 
     def _ensure_writer(self) -> Queue:
@@ -346,34 +364,37 @@ class ExecutionEngine:
         return self._wq
 
     def _busy_time_locked(self, now: float) -> float:
-        """Cumulative dispatch-thread busy seconds up to ``now`` (the
-        writer samples this at finalize start/end to measure overlap)."""
+        """Cumulative dispatch-thread busy seconds up to ``now``, a
+        ``perf_counter`` reading (the writer samples this at finalize
+        start/end to measure overlap)."""
         extra = (now - self._busy_since) if self._busy_since is not None \
             else 0.0
         return self._busy_accum + extra
 
     def _execute(self, batch: List[PendingTask], reason: str) -> None:
-        t0 = time.monotonic()
         deferred = getattr(self.runtime, "execute_real_many_deferred", None)
         if deferred is not None:
             # pipelined path: dispatch device compute here, hand the host
             # sync + bundle writes + once-markers (finalize) to the writer
             # thread, and loop straight to the next batch.  Handles resolve
             # only after finalize — ack-after-durable is preserved.
-            with self._cv:
-                self._busy_since = t0
+            lo = min(task_lo(p.task) for p in batch)
             finalize = None
-            try:
-                finalize = deferred([p.task for p in batch])
-            except BaseException:
-                pass  # compute-stage failure: writer runs per-task fallback
-            finally:
-                now = time.monotonic()
+            with spans.span("engine.dispatch", batch=lo) as sp:
                 with self._cv:
-                    self._busy_accum += now - self._busy_since
-                    self._busy_since = None
-            self._ensure_writer().put((batch, finalize, reason, now - t0))
+                    self._busy_since = sp.t0
+                try:
+                    finalize = deferred([p.task for p in batch])
+                except BaseException:
+                    pass  # compute-stage failure: writer runs per-task fallback
+            with self._cv:
+                self._busy_accum += sp.t1 - sp.t0
+                self._busy_since = None
+            with spans.span("engine.handoff", batch=lo):
+                self._ensure_writer().put(
+                    (batch, finalize, reason, sp.t1 - sp.t0))
             return
+        t0 = time.monotonic()
         outcomes = self._run_fallback_capable(batch, fused=True)
         self._finish(batch, outcomes, reason,
                      exec_dt=time.monotonic() - t0)
@@ -412,28 +433,30 @@ class ExecutionEngine:
             if item is None:
                 return
             batch, finalize, reason, exec_dt = item
-            tf0 = time.monotonic()
-            with self._cv:
-                b0 = self._busy_time_locked(tf0)
-            if finalize is not None:
-                try:
-                    finalize()
-                    outcomes: List[Optional[BaseException]] = \
-                        [None] * len(batch)
-                except BaseException:
-                    finalize = None  # fall through to per-task isolation
-            if finalize is None:
-                outcomes = self._run_fallback_capable(batch, fused=False)
-            tf1 = time.monotonic()
+            lo = min(task_lo(p.task) for p in batch)
+            # one pair of clock reads times the writer for both the span
+            # and write_s
+            with spans.span("engine.write", batch=lo) as sp:
+                with self._cv:
+                    b0 = self._busy_time_locked(sp.t0)
+                if finalize is not None:
+                    try:
+                        finalize()
+                        outcomes: List[Optional[BaseException]] = \
+                            [None] * len(batch)
+                    except BaseException:
+                        finalize = None  # fall through to per-task isolation
+                if finalize is None:
+                    outcomes = self._run_fallback_capable(batch, fused=False)
+            write_s = sp.t1 - sp.t0
             with self._cv:
                 # overlap = dispatch-thread busy time during this finalize:
                 # the write seconds hidden behind the next batch's compute
-                overlap = self._busy_time_locked(tf1) - b0
+                overlap = self._busy_time_locked(sp.t1) - b0
                 self._stats["deferred_batches"] += 1
-                self._stats["write_s"] += tf1 - tf0
+                self._stats["write_s"] += write_s
                 self._stats["write_overlap_s"] += max(0.0, overlap)
-            self._finish(batch, outcomes, reason,
-                         exec_dt=exec_dt + (tf1 - tf0))
+            self._finish(batch, outcomes, reason, exec_dt=exec_dt + write_s)
 
     def _finish(self, batch: List[PendingTask],
                 outcomes: List[Optional[BaseException]], reason: str,
@@ -468,6 +491,9 @@ class ExecutionEngine:
         up = (time.monotonic() - t0) if t0 is not None else 0.0
         s["uptime_s"] = up
         s["utilization"] = (s["exec_s"] / up) if up > 0 else 0.0
+        for name, t in spans.totals().items():
+            s[f"span_s.{name}"] = t["s"]
+            s[f"span_n.{name}"] = t["n"]
         return s
 
     def __enter__(self) -> "ExecutionEngine":

@@ -59,7 +59,6 @@ identical to per-task execution.
 from __future__ import annotations
 
 import threading
-import time
 import weakref
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -67,6 +66,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import spans
 from repro.core.bundler import Bundler
 
 # process-wide 1-D device mesh (multi-device dispatch) ------------------------
@@ -158,8 +158,7 @@ class EnsembleExecutor:
         self.bucketed = bucketed
         self.share_cache = share_cache
         self._private_jit: Dict[Tuple, Callable] = {}
-        self.stats = {"bundles": 0, "samples": 0, "sim_time": 0.0,
-                      "write_s": 0.0,
+        self.stats = {"bundles": 0, "samples": 0,
                       "compiles": 0, "launches": 0, "padded_samples": 0,
                       "mesh_launches": 0,
                       "devices": 1 if self.mesh is None
@@ -174,10 +173,14 @@ class EnsembleExecutor:
             n % int(self.mesh.shape[self.data_axis]) == 0
 
     def _build(self, n: int) -> Callable:
-        def run(batch, seeds):
+        # the device program is named merlin_ensemble (the jit's module
+        # name and the ops' name scope) whatever the simulator is, so a
+        # trace reader finds the launch after a refactor
+        def merlin_ensemble(batch, seeds):
             _count_trace()
-            rngs = jax.vmap(jax.random.PRNGKey)(seeds)
-            return jax.vmap(self.simulator)(batch, rngs)
+            with jax.named_scope("merlin_ensemble"):
+                rngs = jax.vmap(jax.random.PRNGKey)(seeds)
+                return jax.vmap(self.simulator)(batch, rngs)
 
         # donation frees the input buffers for reuse by the outputs; XLA on
         # CPU can't honor it and warns, so only donate on real accelerators
@@ -190,10 +193,10 @@ class EnsembleExecutor:
             # multi-device equivalence test asserts exactly that.
             from jax.sharding import PartitionSpec as P
             spec = P(self.data_axis)
-            sharded = jax.shard_map(run, mesh=self.mesh,
+            sharded = jax.shard_map(merlin_ensemble, mesh=self.mesh,
                                     in_specs=(spec, spec), out_specs=spec)
             return jax.jit(sharded, donate_argnums=donate)
-        return jax.jit(run, donate_argnums=donate)
+        return jax.jit(merlin_ensemble, donate_argnums=donate)
 
     def _compiled(self, n: int) -> Callable:
         """The jitted vmapped simulator for padded size n (cached; shared
@@ -225,22 +228,26 @@ class EnsembleExecutor:
         ``defer_write=True`` (bundler only) dispatches the compute and
         returns a zero-arg closure that performs the host sync + bundle
         writes when called — the engine's writer thread runs it so the
-        write of this bundle overlaps the dispatch of the next one
-        (``stats["write_s"]`` accumulates on the closure's thread).
+        write of this bundle overlaps the dispatch of the next one.
+
+        Spans (:mod:`repro.core.spans`, ``batch=lo``): ``executor.launch``
+        from padding the rows to the dispatch of the slices that drop
+        the padding, ``executor.host_sync`` for the wait on the device
+        and the copy to the host.
         """
-        t0 = time.monotonic()
         n = hi - lo
         samples = np.asarray(samples)
         if len(samples) != n:
             raise ValueError(f"sample block has {len(samples)} rows "
                              f"for range [{lo}, {hi})")
         padded = bucket_for(n) if self.bucketed else n
-        batch = jnp.asarray(pad_rows(samples, padded))
-        # seeds beyond hi are masked work: their outputs are sliced away
-        seeds = jnp.arange(lo, lo + padded, dtype=jnp.uint32)
-        out = self._compiled(padded)(batch, seeds)
-        if padded != n:
-            out = jax.tree.map(lambda a: a[:n], out)
+        with spans.span("executor.launch", batch=lo):
+            batch = jnp.asarray(pad_rows(samples, padded))
+            # seeds beyond hi are masked work: their outputs are sliced away
+            seeds = jnp.arange(lo, lo + padded, dtype=jnp.uint32)
+            out = self._compiled(padded)(batch, seeds)
+            if padded != n:
+                out = jax.tree.map(lambda a: a[:n], out)
         self.stats["bundles"] += 1
         self.stats["samples"] += n
         self.stats["padded_samples"] += padded - n
@@ -248,25 +255,22 @@ class EnsembleExecutor:
         if self._mesh_divides(padded):
             self.stats["mesh_launches"] += 1
         if self.bundler is not None:
-            spans = tuple(sub_ranges or ((lo, hi),))
+            ranges = tuple(sub_ranges or ((lo, hi),))
 
             def finish_write(dev_out=out):
-                tw = time.monotonic()
-                jax.block_until_ready(dev_out)  # sync once, at write time
-                host = jax.tree.map(np.asarray, dev_out)
-                for slo, shi in spans:
+                with spans.span("executor.host_sync", batch=lo):
+                    jax.block_until_ready(dev_out)  # sync once, at write time
+                    host = jax.tree.map(np.asarray, dev_out)
+                for slo, shi in ranges:
                     sl = slice(slo - lo, shi - lo)
                     self.bundler.write_bundle(
                         slo, shi, {k: v[sl] for k, v in host.items()})
-                self.stats["write_s"] += time.monotonic() - tw
                 return host
             if defer_write:
-                self.stats["sim_time"] += time.monotonic() - t0
                 return finish_write
             out = finish_write()
         elif block:
             out = jax.tree.map(np.asarray, out)
-        self.stats["sim_time"] += time.monotonic() - t0
         return out
 
     def step_fn(self) -> Callable:

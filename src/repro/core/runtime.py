@@ -35,7 +35,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.core import hierarchy as H
-from repro.core import jsonstore
+from repro.core import jsonstore, spans
 from repro.core.dag import DagNode, TaskDag, compile_dag
 from repro.core.handlers import ExecutionHandler, default_handlers
 from repro.core.queue import (PRIORITY_GEN, PRIORITY_REAL, BrokerError,
@@ -831,8 +831,9 @@ class MerlinRuntime:
         def finalize() -> None:
             ctx.run_deferred()
             for t in run:  # per-sub-bundle markers + node accounting
-                if self.counters.once(self._done_key(t)):
-                    self._bundle_done(t)
+                with spans.span("runtime.mark", task=t.payload["samples"][0]):
+                    if self.counters.once(self._done_key(t)):
+                        self._bundle_done(t)
         if deferred:
             return finalize
         finalize()

@@ -62,7 +62,8 @@ import time
 from typing import List, Optional, Sequence
 
 from repro.core import hierarchy as H
-from repro.core.engine import EngineClosed, ExecutionEngine
+from repro.core import spans
+from repro.core.engine import EngineClosed, ExecutionEngine, task_lo
 from repro.core.queue import (BrokerError, BrokerFull, Lease, Task,
                               dlq_queue_name)
 from repro.core.resilience import BackoffPolicy, RetryPolicy
@@ -149,7 +150,8 @@ class Worker(threading.Thread):
         if not self._pending_acks:
             return
         try:
-            broker.ack_many(self._pending_acks)
+            with spans.span("worker.ack"):
+                broker.ack_many(self._pending_acks)
         except BrokerError:
             self.stats["broker_retries"] += 1
             # keep them for the next iteration; cap the backlog — anything
@@ -176,9 +178,10 @@ class Worker(threading.Thread):
             if self._pending_acks:
                 self._flush_acks(broker, [])
             try:
-                leases = broker.get_many(self.batch,
-                                         timeout=self.poll_timeout,
-                                         queues=self.queues)
+                with spans.span("worker.lease"):
+                    leases = broker.get_many(self.batch,
+                                             timeout=self.poll_timeout,
+                                             queues=self.queues)
             except BrokerError:
                 # broker down (BrokerUnavailable) or a transient server-side
                 # failure relayed as a structured error: back off and keep
@@ -279,9 +282,11 @@ class Worker(threading.Thread):
                 acks.extend(self._execute_reals_inline(direct, broker))
             if pendings is not None:
                 for lease, p in zip(fusable, pendings):
-                    # dispatch is deadline-bounded (max_wait_ms), so this
-                    # wait is short unless the device itself is busy
-                    p.wait()
+                    # the handle resolves once the batch's bundles are
+                    # durable, so this wait spans the engine's dispatch
+                    # and its writer's finalize
+                    with spans.span("worker.wait", task=task_lo(lease.task)):
+                        p.wait()
                     if isinstance(p.error, EngineClosed):
                         continue  # never executed: lease expiry redelivers
                     if p.error is None:
