@@ -29,6 +29,9 @@ ids — hold cursors within one aggregation epoch.
 from __future__ import annotations
 
 import os
+import threading
+from concurrent.futures import Future, wait
+from queue import SimpleQueue
 from typing import Dict, Iterable, List, Mapping, Optional, Sequence, Set, Tuple
 
 import numpy as np
@@ -37,6 +40,63 @@ from repro.core import spans
 
 # a file's identity-and-content signature: (inode, mtime_ns, size)
 Sig = Tuple[int, int, int]
+
+# Deflating a file whose arrays hold this many bytes costs many times a
+# thread hand-off, so a group of such files is compressed side by side on
+# the writer pool; a group with any smaller file is written inline.
+POOL_MIN_FILE_BYTES = 256 * 1024
+
+
+class _WriterPool:
+    """Daemon threads running submitted calls; never holds up exit."""
+
+    def __init__(self, n: int):
+        self._q: SimpleQueue = SimpleQueue()
+        for i in range(n):
+            threading.Thread(target=self._loop, daemon=True,
+                             name=f"merlin-bundle-writer-{i}").start()
+
+    def submit(self, fn, *args) -> Future:
+        fut: Future = Future()
+        self._q.put((fut, fn, args))
+        return fut
+
+    def _loop(self) -> None:
+        while True:
+            fut, fn, args = self._q.get()
+            try:
+                fut.set_result(fn(*args))
+            except BaseException as e:  # re-raised by the caller's result()
+                fut.set_exception(e)
+
+
+_POOL: Optional[_WriterPool] = None
+_POOL_LOCK = threading.Lock()
+# set on a thread while it writes one file of a write_bundles group, whose
+# sink pushes wait until every file of the group is renamed
+_IN_GROUP = threading.local()
+
+
+def _writer_pool() -> _WriterPool:
+    """The process's bundle-writer pool, started on first use."""
+    global _POOL
+    with _POOL_LOCK:
+        if _POOL is None:
+            _POOL = _WriterPool(min(8, os.cpu_count() or 1))
+        return _POOL
+
+
+def _forget_pool() -> None:
+    # a forked child inherits the pool object but none of its threads
+    global _POOL, _POOL_LOCK
+    _POOL, _POOL_LOCK = None, threading.Lock()
+
+
+os.register_at_fork(after_in_child=_forget_pool)
+
+
+def _nbytes(results: Mapping[str, np.ndarray]) -> int:
+    return sum(getattr(v, "nbytes", 0) for v in results.values())
 
 
 class Bundler:
@@ -67,7 +127,9 @@ class Bundler:
 
     def write_bundle(self, lo: int, hi: int, results: Dict[str, np.ndarray]) -> str:
         """results: dict of arrays with leading dim == hi-lo.  The
-        compression and the publish are the span ``bundler.write``."""
+        compression and the publish are the span ``bundler.write``; every
+        bundle file, alone or in a :meth:`write_bundles` group, is written
+        here."""
         d = self.leaf_dir(lo, hi - lo)
         path = os.path.join(d, f"bundle_{lo:09d}_{hi:09d}.npz")
         # np.savez appends ".npz" unless present: keep the suffix on the tmp
@@ -76,12 +138,58 @@ class Bundler:
         with spans.span("bundler.write", task=lo):
             np.savez_compressed(tmp, _sample_ids=ids, **results)
             os.rename(tmp, path)  # atomic publish
-        if self.sink is not None:
+        if not getattr(_IN_GROUP, "on", False):
+            self._feed_sink(((lo, hi, results),))
+        return path
+
+    def write_bundles(self, items: Sequence[Tuple[int, int, Dict[str, np.ndarray]]]
+                      ) -> List[str]:
+        """Write one bundle file per ``(lo, hi, results)`` (the files of one
+        fused launch) under the span ``bundler.write_many``, ``batch`` the
+        group's lowest id; return their paths in order.
+
+        Returns only once every file is renamed into place, so samples
+        marked or acked after it are durable.  When there are two or more
+        files and each holds at least ``POOL_MIN_FILE_BYTES``, the files
+        are compressed side by side on the process's writer pool (deflate
+        and crc32 release the GIL); otherwise one after another on this
+        thread.  If a file fails, the first error in order is raised only
+        after every file of the group has finished, so a rerun of the group
+        never races a pool thread on a tmp name.  The sink is then fed on
+        this thread, in order.
+        """
+        if not items:
+            return []
+        pooled = len(items) > 1 and min(
+            _nbytes(r) for _, _, r in items) >= POOL_MIN_FILE_BYTES
+        with spans.span("bundler.write_many", batch=min(lo for lo, _, _ in items)):
+            if pooled:
+                futs = [_writer_pool().submit(self._write_in_group, *it)
+                        for it in items]
+                wait(futs)
+                paths = [f.result() for f in futs]
+            else:
+                paths = [self._write_in_group(*it) for it in items]
+        self._feed_sink(items)
+        return paths
+
+    def _write_in_group(self, lo: int, hi: int,
+                        results: Dict[str, np.ndarray]) -> str:
+        _IN_GROUP.on = True
+        try:
+            return self.write_bundle(lo, hi, results)
+        finally:
+            _IN_GROUP.on = False
+
+    def _feed_sink(self, items: Iterable[Tuple[int, int, Dict[str, np.ndarray]]]
+                   ) -> None:
+        if self.sink is None:
+            return
+        for lo, hi, results in items:
             try:
                 self.sink.push_bundle(lo, hi, results)
             except Exception:
-                pass  # the file above is the durable record; sink is best-effort
-        return path
+                pass  # the file is the durable record; sink is best-effort
 
     # -- aggregation ----------------------------------------------------------
     def aggregate_leaf(self, leaf_dir: str) -> Optional[str]:

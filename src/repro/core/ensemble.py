@@ -261,10 +261,9 @@ class EnsembleExecutor:
                 with spans.span("executor.host_sync", batch=lo):
                     jax.block_until_ready(dev_out)  # sync once, at write time
                     host = jax.tree.map(np.asarray, dev_out)
-                for slo, shi in ranges:
-                    sl = slice(slo - lo, shi - lo)
-                    self.bundler.write_bundle(
-                        slo, shi, {k: v[sl] for k, v in host.items()})
+                self.bundler.write_bundles(
+                    [(slo, shi, {k: v[slo - lo:shi - lo] for k, v in host.items()})
+                     for slo, shi in ranges])
                 return host
             if defer_write:
                 return finish_write

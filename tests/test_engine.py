@@ -9,13 +9,15 @@ import sys
 import threading
 import time
 
+import jax
 import numpy as np
 import pytest
 
 from repro.core import ensemble as E
 from repro.core.bundler import Bundler
 from repro.core.engine import (ContinuousBatcher, DeadlineExpired,
-                               EngineClosed, ExecutionEngine)
+                               EngineClosed, ExecutionEngine, PendingTask,
+                               task_lo)
 from repro.core.hierarchy import HierarchyCfg
 from repro.core.queue import PRIORITY_REAL, BrokerFull, new_task
 from repro.core.resilience import RetryPolicy
@@ -502,6 +504,104 @@ def test_deferred_writes_overlap_next_dispatch():
             "finalize never overlapped a dispatch"
     finally:
         eng.close()
+
+
+class _SlowBundler(Bundler):
+    """Slowed file writes, slowest on the writer pool; records each group's
+    files, which files a pool thread wrote, and any two writes of one file
+    (one tmp name) at once.  With ``fail_first_group`` the first file of
+    the first multi-file group fails fast, once."""
+
+    def __init__(self, root, fail_first_group=False):
+        super().__init__(root)
+        self.lock = threading.Lock()
+        self.groups = []
+        self.in_flight = set()
+        self.collisions = []
+        self.pool_files = 0
+        self.fail_first_group = fail_first_group
+        self.failed = None
+
+    def write_bundles(self, items):
+        with self.lock:
+            self.groups.append([(lo, hi) for lo, hi, _ in items])
+            if self.fail_first_group and len(items) > 1:
+                self.fail_first_group = False
+                self.failed = items[0][:2]
+        return super().write_bundles(items)
+
+    def write_bundle(self, lo, hi, results):
+        pooled = threading.current_thread().name.startswith(
+            "merlin-bundle-writer-")
+        with self.lock:
+            if (lo, hi) in self.in_flight:
+                self.collisions.append((lo, hi))
+            self.in_flight.add((lo, hi))
+            fail = (lo, hi) == self.failed and pooled
+            self.pool_files += pooled
+        try:
+            if fail:
+                raise OSError(f"disk full at {lo}")
+            time.sleep(0.3 if pooled else 0.02)
+            return super().write_bundle(lo, hi, results)
+        finally:
+            with self.lock:
+                self.in_flight.discard((lo, hi))
+
+    def path(self, lo, hi):
+        return os.path.join(self.leaf_dir(lo, hi - lo),
+                            f"bundle_{lo:09d}_{hi:09d}.npz")
+
+
+def _wide_sim(u, rng):
+    """32 KB a sample: a 10-sample bundle file passes the pool's threshold."""
+    return {"v": jax.random.normal(rng, (8192,)) + u.sum(), "inputs": u}
+
+
+@pytest.mark.parametrize("fail_first_group", [False, True],
+                         ids=["pooled", "finalize_fails"])
+def test_deferred_pooled_writes_ack_after_durable(tmp_path, monkeypatch,
+                                                   fail_first_group):
+    """Through the engine's deferred path with the writer pool on: no
+    handle of a fused batch resolves before every file of that batch is
+    on disk.  A group whose file fails falls back per task, and the rerun
+    never writes a file (its tmp name) while a pool thread still is."""
+    b = _SlowBundler(str(tmp_path / "res"), fail_first_group)
+    early = []
+    resolve = PendingTask._resolve
+
+    def checked_resolve(self, error):
+        lo = task_lo(self.task)
+        if error is None and lo >= 0:
+            with b.lock:
+                groups = [g for g in b.groups if any(r[0] == lo for r in g)]
+            early.extend(r for g in groups for r in g
+                         if not os.path.exists(b.path(*r)))
+        resolve(self, error)
+
+    monkeypatch.setattr(PendingTask, "_resolve", checked_resolve)
+    rt = MerlinRuntime(workspace=str(tmp_path),
+                       hierarchy=HierarchyCfg(max_fanout=16, bundle=10))
+    rt.register("sim", E.EnsembleExecutor(_wide_sim, b).step_fn())
+    spec = StudySpec(name="wide", steps=[Step(name="sim", fn="sim")])
+    samples = np.random.default_rng(3).random((80, 3)).astype(np.float32)
+    # compile the one-task program first, so a per-task rerun writes while
+    # a pool thread of the failed group would still be writing
+    E.EnsembleExecutor(_wide_sim, None).run_bundle(0, 10, samples[:10])
+    with WorkerPool(rt, n_workers=1, batch=8) as pool:
+        sid = rt.run(spec, samples)
+        assert rt.wait(sid, timeout=120) and pool.drain(timeout=60)
+        eng = pool.stats()["engine"]
+    assert eng["deferred_batches"] >= 1 and eng["failed_tasks"] == 0
+    assert max(len(g) for g in b.groups) > 1 and b.pool_files > 1
+    assert early == [] and b.collisions == []
+    if fail_first_group:  # the fused group failed, then each task ran alone
+        tries = [g for g in b.groups if b.failed in g]
+        assert len(tries[0]) > 1 and tries[-1] == [b.failed]
+    present, corrupt = b.crawl()
+    assert present == set(range(80)) and not corrupt
+    names = [f for _, _, fs in os.walk(b.root) for f in fs]
+    assert len(names) == 8 and not [f for f in names if ".tmp-" in f]
 
 
 # ---------------------------------------------------------------------------
